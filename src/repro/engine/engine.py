@@ -51,6 +51,7 @@ from repro.core.flat import FlatSpec, flat_spec
 from repro.engine.phases import Phase
 from repro.engine.steps import (make_fused_dbl_step, make_fused_phase_scan,
                                 make_micro_step, make_weighted_step)
+from repro.launch.mesh import auto_axes
 from repro.optim import Optimizer
 
 
@@ -102,7 +103,9 @@ class TrainEngine:
       background thread while the current phase runs (no-mesh paths; needs
       a ``batch_struct``-capable batch_fn such as ``DataPlane``).  The
       boundary stall either way lands in ``engine.stall_log`` as
-      ``{"phase", "kind", "stall_s", "warm"}`` records.
+      ``{"phase", "kind", "stall_s", "warm"}`` records.  A warm compile
+      that fails is counted in ``warm_errors`` and what it raised is kept
+      in ``warm_exceptions``; the boundary then compiles cold.
     precision: ``"f32"`` (default — every path bit-identical to before the
       knob existed) or ``"bf16"``: the scan loop carries a bf16 flat store
       (half the parameter HBM) plus the donated f32 master carry, and the
@@ -152,7 +155,11 @@ class TrainEngine:
             raise ValueError(
                 "precision='bf16' requires the fused scan path "
                 "(scan_loop enabled, fused_merge on, no mesh)")
+        if mesh is not None:
+            self.mesh = auto_axes(mesh)
         self._cache: dict = {}
+        # mesh runs: the shardings params / opt_state / batch were put on
+        self.placement: dict = {}
         self._phase_cache: dict = {}
         self._warm_steps: dict = {}
         self._inflight: dict = {}
@@ -162,6 +169,7 @@ class TrainEngine:
         self.warm_scheduled = 0
         self.warm_hits = 0
         self.warm_errors = 0
+        self.warm_exceptions: list = []     # what each failed warm raised
         self.stall_log: list = []
 
     # ------------------------------------------------------------------
@@ -213,6 +221,12 @@ class TrainEngine:
         kw = {}
         if self.donate:
             kw["donate_argnums"] = donate
+        if self.placement:
+            # keep params / optimizer state where the engine placed them:
+            # the update kernel runs on whole leaves, so unpinned outputs
+            # come back replicated and the next step recompiles for them
+            kw["out_shardings"] = (self.placement["params"],
+                                   self.placement["opt_state"], None)
         jitted = jax.jit(fn, static_argnums=static, **kw)
         self.compile_count += 1
         return jitted
@@ -230,7 +244,7 @@ class TrainEngine:
                                      drop_rate=key.drop_rate,
                                      fused=self.fused_merge is not False,
                                      interpret=self.interpret,
-                                     leafwise=self.mesh is not None)
+                                     mesh=self.mesh)
             return fn, (3,), (0, 1)          # lr baked into the kernel
         fn = make_weighted_step(self.cfg, self.optimizer,
                                 layout=key.layout,
@@ -271,6 +285,14 @@ class TrainEngine:
                 self._phase_cache[ck] = self._phase_scan_jit(phase, spec)
                 self.compile_count += 1
             return self._phase_cache[ck]
+
+    @property
+    def phase_executables(self) -> list:
+        """The AOT-compiled whole-phase scans in the cache (their
+        ``as_text()`` shows whether the update ran as the Pallas kernel)."""
+        with self._lock:
+            return [fn for fn in self._phase_cache.values()
+                    if not _is_lazy(fn)]
 
     @property
     def cache_size(self) -> int:
@@ -355,9 +377,8 @@ class TrainEngine:
                 try:
                     jitted = self._phase_scan_jit(phase, spec)
                     compiled = jitted.lower(p2s, v2s, bst, rst).compile()
-                except Exception:           # noqa: BLE001 — warm is advisory
-                    with self._lock:
-                        self.warm_errors += 1
+                except Exception as e:      # noqa: BLE001 — warm is advisory
+                    self._warm_failed(e)
                     return None
                 with self._lock:
                     self._phase_cache[ck] = compiled
@@ -369,6 +390,14 @@ class TrainEngine:
                 self.warm_scheduled += 1
             scheduled = True
         return scheduled
+
+    def _warm_failed(self, exc: BaseException) -> None:
+        """A failed warm compile falls back to a cold compile at the
+        boundary; keep what it raised (an out-of-memory compile would
+        otherwise vanish into the cold retry)."""
+        with self._lock:
+            self.warm_errors += 1
+            self.warm_exceptions.append(exc)
 
     def _warm_step_key(self, key: StepKey, phase: Phase):
         # fused per-step executables bake lr in (static argnum); the warm
@@ -404,9 +433,8 @@ class TrainEngine:
                                c=compiled: c(p, s, b, rng))
                 else:
                     wrapped = compiled
-            except Exception:               # noqa: BLE001 — warm is advisory
-                with self._lock:
-                    self.warm_errors += 1
+            except Exception as e:          # noqa: BLE001 — warm is advisory
+                self._warm_failed(e)
                 return None
             with self._lock:
                 self._warm_steps[wkey] = wrapped
@@ -428,7 +456,8 @@ class TrainEngine:
         t0 = time.perf_counter()
         try:
             entry = fut.result()
-        except Exception:                   # noqa: BLE001
+        except Exception as e:              # noqa: BLE001
+            self._warm_failed(e)
             entry = None
         return entry, time.perf_counter() - t0
 
@@ -732,17 +761,14 @@ class TrainEngine:
                     batch = dict(batch, weight=weights)
                 drop_rng = (jax.random.fold_in(rng, gstep)
                             if drop > 0 else None)
-                if step is None:
-                    if self.mesh is None:
-                        # acquire an AOT executable from the first batch —
-                        # warm (background-compiled), cached, or cold; the
-                        # boundary stall is measured either way
-                        step, stall, warm = self._acquire_step_fn(
-                            phase, params, opt_state, batch, drop_rng)
-                        self._record_stall(pi + phase_offset, "step",
-                                           stall, warm)
-                    else:
-                        step = self.step_fn(phase)
+                if step is None and self.mesh is None:
+                    # acquire an AOT executable from the first batch —
+                    # warm (background-compiled), cached, or cold; the
+                    # boundary stall is measured either way
+                    step, stall, warm = self._acquire_step_fn(
+                        phase, params, opt_state, batch, drop_rng)
+                    self._record_stall(pi + phase_offset, "step", stall,
+                                       warm)
                 if self.mesh is not None:
                     if placed is None:
                         psh, osh, bsh = self._shardings(params, opt_state,
@@ -757,6 +783,10 @@ class TrainEngine:
                             lambda s: NamedSharding(self.mesh, s),
                             batch_specs(batch, self.mesh))
                     batch = jax.device_put(batch, bsh)
+                    if step is None:
+                        self.placement = {"params": psh, "opt_state": osh,
+                                          "batch": bsh}
+                        step = self.step_fn(phase)
                 params, opt_state, metrics = step(params, opt_state, batch,
                                                   phase.lr, drop_rng)
                 gstep += 1

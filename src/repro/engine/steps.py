@@ -34,6 +34,7 @@ compiles the entire inner loop into one executable with exactly one
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -74,13 +75,15 @@ def _small_valid_index(layout) -> np.ndarray:
 
 def make_fused_dbl_step(cfg, layout, *, drop_rate: float = 0.0,
                         fused: bool = True, interpret: Optional[bool] = None,
-                        leafwise: bool = False):
+                        mesh=None):
     """SGD dual-batch step with the fused ``dbl_merge`` parameter update on
     the hot path (paper §3.4).  ``opt_state`` passes through untouched — the
     server update IS the optimizer.  ``fused=False`` selects the unfused
-    reference update (flag for perf comparison / debugging); ``leafwise``
-    keeps the per-leaf kernel form for mesh-sharded params (the flat-store
-    concat would break their shardings)."""
+    reference update (flag for perf comparison / debugging).  With a
+    ``mesh`` the params are sharded by ``launch.sharding.param_specs``: the
+    kernel then runs per leaf on each device's own shards under
+    ``shard_map`` — the compiler cannot partition a Mosaic kernel, and the
+    flat-store concat would break the shardings."""
     from repro.kernels.dbl_merge import dbl_merge_tree
     from repro.kernels.ref import dbl_merge_ref
 
@@ -106,10 +109,19 @@ def make_fused_dbl_step(cfg, layout, *, drop_rate: float = 0.0,
         (loss_l, _), g_large = group_grad(params, batch,
                                           jnp.arange(nl_rows), rng)
         (loss_s, _), g_small = group_grad(params, batch, small_idx, rng)
-        if fused:
+        if fused and mesh is not None:
+            from repro.launch.sharding import param_specs
+            specs = param_specs(params, mesh)
+            merge = functools.partial(dbl_merge_tree, factor=f, lr=lr_f,
+                                      interpret=interpret, leafwise=True)
+            # check_vma off: the kernel's outputs carry no varying-axes
+            # annotation; each shard's update is local by construction
+            params = jax.shard_map(merge, mesh=mesh, in_specs=(specs,) * 3,
+                                   out_specs=specs, check_vma=False)(
+                params, g_large, g_small)
+        elif fused:
             params = dbl_merge_tree(params, g_large, g_small, factor=f,
-                                    lr=lr_f, interpret=interpret,
-                                    leafwise=leafwise)
+                                    lr=lr_f, interpret=interpret)
         else:
             params = jax.tree_util.tree_map(
                 lambda p, gl, gs: dbl_merge_ref(p, gl, gs, factor=f,

@@ -463,6 +463,9 @@ def dbl_merge_tree(params, g_large, g_small, *, factor: float, lr: float,
     ``leafwise=True`` applies the same kernel per leaf instead: the flat
     concat would destroy per-leaf shardings (XLA falls back to a full
     rematerialization), so mesh-sharded trees keep the leaf-at-a-time form.
+    On TPU the compiler cannot partition the kernel itself, so a sharded
+    tree is updated under ``shard_map``, each device on its own shards
+    (``engine.steps.make_fused_dbl_step``).
     """
     if leafwise:
         return jax.tree_util.tree_map(

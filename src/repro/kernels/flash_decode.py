@@ -148,8 +148,18 @@ def flash_decode(q, k_cache, v_cache, pos, *, window: int = 0,
 # ------------------------- paged decode --------------------------------
 def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, page_len: int, n_pages_slot: int,
-                  window: int, scale: float):
-    pi = pl.program_id(2)
+                  n_rep: int, window: int, scale: float):
+    """One grid step = one slot x one page, every head at once.
+
+    The K/V block is the whole ``(page_len, KV, hd)`` page, so its last two
+    dims equal the pool's and the TPU tiling rule holds for any KV.  Query
+    head ``g * n_rep + r`` reads KV head ``g``; the wrapper hands the
+    queries in as ``(n_rep, KV, hd)`` so each of the ``n_rep`` passes below
+    lines its heads up with the page's KV axis.  Scores keep a trailing
+    unit lane dim, ``(page_len, KV, 1)``, so every softmax statistic
+    broadcasts against ``(KV, hd)`` rows without a relayout.
+    """
+    pi = pl.program_id(1)
     si = pl.program_id(0)
 
     @pl.when(pi == 0)
@@ -162,31 +172,32 @@ def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     k_start = pi * page_len       # LOGICAL position of this page's 1st token
 
     def compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale       # (1, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)            # (page_len, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, page_len), 1)
+        k = k_ref[0].astype(jnp.float32)                 # (page_len, KV, hd)
+        v = v_ref[0].astype(jnp.float32)
+        kpos = k_start + jax.lax.broadcasted_iota(
+            jnp.int32, (page_len, 1, 1), 0)
         valid = kpos <= pos
         if window > 0:
             valid = jnp.logical_and(valid, kpos > pos - window)
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = m_cur
-        acc_ref[...] = acc_ref[...] * alpha \
-            + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())))
+        for r in range(n_rep):
+            q = q_ref[0, r].astype(jnp.float32) * scale  # (KV, hd)
+            s = jnp.sum(k * q[None], axis=-1, keepdims=True)  # (pl, KV, 1)
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[r]                            # (KV, 1)
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=0))
+            alpha = jnp.exp(m_prev - m_cur)
+            p = jnp.exp(s - m_cur[None])
+            l_ref[r] = l_ref[r] * alpha + jnp.sum(p, axis=0)
+            m_ref[r] = m_cur
+            acc_ref[r] = acc_ref[r] * alpha + jnp.sum(p * v, axis=0)
 
     # pages wholly beyond the slot's live range contribute nothing; skip
     pl.when(k_start <= pos)(compute)
 
     @pl.when(pi == n_pages_slot - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...]
+                    / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def flash_decode_paged(q, k_pages, v_pages, page_table, lengths, *,
@@ -202,6 +213,10 @@ def flash_decode_paged(q, k_pages, v_pages, page_table, lengths, *,
     page_table: (S, PP) int32         pool page id of each logical page
     lengths:    (S,) int32            per-slot position of the newest token
                                       (mask: logical index <= lengths[s])
+
+    The grid is ``(S, PP)``: each step DMAs one whole page — all KV heads —
+    and updates every query head's online softmax, so a GQA page is read
+    once per slot, not once per query head.
 
     Off-TPU (``impl="auto"``) this dispatches to the XLA reference
     (``paged_decode_ref``) — gather + masked softmax, honest CPU work —
@@ -220,35 +235,36 @@ def flash_decode_paged(q, k_pages, v_pages, page_table, lengths, *,
     scale = hd ** -0.5
     table = jnp.asarray(page_table, jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32)
+    # head g * n_rep + r -> [r, g]: each r-slice lines up with the KV axis
+    qg = q.reshape(ns, kvh, n_rep, hd).transpose(0, 2, 1, 3)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(ns, h, pp),
+        grid=(ns, pp),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, hd),
-                         lambda si, hi, pi, tbl, ln: (si, hi, 0, 0)),
-            pl.BlockSpec((1, page_len, 1, hd),
-                         lambda si, hi, pi, tbl, ln:
-                         (tbl[si, pi], 0, hi // n_rep, 0)),
-            pl.BlockSpec((1, page_len, 1, hd),
-                         lambda si, hi, pi, tbl, ln:
-                         (tbl[si, pi], 0, hi // n_rep, 0)),
+            pl.BlockSpec((1, n_rep, kvh, hd),
+                         lambda si, pi, tbl, ln: (si, 0, 0, 0)),
+            pl.BlockSpec((1, page_len, kvh, hd),
+                         lambda si, pi, tbl, ln: (tbl[si, pi], 0, 0, 0)),
+            pl.BlockSpec((1, page_len, kvh, hd),
+                         lambda si, pi, tbl, ln: (tbl[si, pi], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, hd),
-                               lambda si, hi, pi, tbl, ln: (si, hi, 0, 0)),
+        out_specs=pl.BlockSpec((1, n_rep, kvh, hd),
+                               lambda si, pi, tbl, ln: (si, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, hd), jnp.float32),
+            pltpu.VMEM((n_rep, kvh, 1), jnp.float32),
+            pltpu.VMEM((n_rep, kvh, 1), jnp.float32),
+            pltpu.VMEM((n_rep, kvh, hd), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_paged_kernel, page_len=page_len, n_pages_slot=pp,
-                          window=window, scale=scale),
+                          n_rep=n_rep, window=window, scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((ns, h, 1, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((ns, n_rep, kvh, hd), q.dtype),
         interpret=interpret,
-    )(table, lens, q, k_pages, v_pages)
+    )(table, lens, qg, k_pages, v_pages)
+    return out.transpose(0, 2, 1, 3).reshape(ns, h, 1, hd)
 
 
 def paged_decode_ref(q, k_pages, v_pages, page_table, lengths, *,

@@ -47,12 +47,9 @@ _ARGS_RE = re.compile(r"\(([^)]*)\)")
 
 
 def raw_cost_analysis(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions: newer
-    jaxlibs return a one-element list of dicts, older ones a bare dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """XLA's own cost analysis of ``compiled`` (a dict; empty when the
+    backend reports none)."""
+    return compiled.cost_analysis() or {}
 
 
 def _split_args(argstr: str):

@@ -81,17 +81,26 @@ def generate(cfg, params, prompts, *, gen: int, max_seq: int, greedy=True,
     return jnp.concatenate(out, axis=1)
 
 
-def _serve_continuous(cfg, params, args):
-    from repro.serve import (PageSpec, ServeEngine, repetitive_workload,
-                             shared_prefix_workload, synthetic_workload)
+def make_engine(cfg, params, args):
+    """The ``--engine continuous`` ServeEngine for a ``parse_args``
+    namespace: paged KV pool whose pages are stored in the model's compute
+    dtype (bf16 pages for a bf16 model)."""
+    from repro.models.layers import dtype_of
+    from repro.serve import PageSpec, ServeEngine
     spec = PageSpec(page_len=args.page_len, pages_per_slot=args.pages_per_slot,
-                    n_slots=args.slots)
-    engine = ServeEngine(cfg, params, spec=spec,
-                         prefill_chunk=args.prefill_chunk,
-                         spec_k=args.spec_k,
-                         temperature=args.temperature, top_k=args.top_k,
-                         sample_seed=args.seed,
-                         prefix_share=args.prefix_share)
+                    n_slots=args.slots,
+                    store_dtype=dtype_of(cfg.compute_dtype))
+    return ServeEngine(cfg, params, spec=spec,
+                       prefill_chunk=args.prefill_chunk, spec_k=args.spec_k,
+                       temperature=args.temperature, top_k=args.top_k,
+                       sample_seed=args.seed, prefix_share=args.prefix_share)
+
+
+def _serve_continuous(cfg, params, args):
+    from repro.serve import (repetitive_workload, shared_prefix_workload,
+                             synthetic_workload)
+    engine = make_engine(cfg, params, args)
+    spec = engine.spec
     if args.workload == "repetitive":
         reqs = repetitive_workload(args.seed, args.requests,
                                    vocab=cfg.vocab_size,
@@ -131,7 +140,7 @@ def _serve_continuous(cfg, params, args):
     return recs
 
 
-def run(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCH_IDS), default="gemma3-4b")
     ap.add_argument("--reduced", action="store_true", default=True)
@@ -162,7 +171,11 @@ def run(argv=None):
                     help="top-k truncation for sampled decode (0 = full)")
     ap.add_argument("--prefix-share", action="store_true",
                     help="COW prefix sharing across admitted prompts")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+def run(argv=None):
+    args = parse_args(argv)
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -190,4 +203,6 @@ def run(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -67,7 +67,7 @@ def build_phases(args):
     return build_spec(args).to_phases()
 
 
-def run(argv=None):
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCH_IDS),
                     default="phi3-mini-3.8b")
@@ -109,33 +109,60 @@ def run(argv=None):
                     help="resume from the latest phase-boundary checkpoint "
                          "in --ckpt")
     ap.add_argument("--seed", type=int, default=0)
+    return ap
+
+
+def _sgd_server(args) -> bool:
+    """Plain-SGD dual-batch -> the paper §3.4 server update (fused
+    dbl_merge hot path).  That update has no momentum/weight-decay state,
+    so the optimizer is built to match — otherwise the CLI would silently
+    claim momentum it never applies.  Stateful optimizers (adamw) keep the
+    weighted-mean path."""
+    return (args.optimizer == "sgd" and args.scheme in ("dbl", "hybrid")
+            and args.micro_steps == 0)
+
+
+def parse_args(argv=None) -> argparse.Namespace:
+    """The CLI's flags, validated (``train`` takes the result)."""
+    ap = _parser()
     args = ap.parse_args(argv)
     if args.resume and not args.ckpt:
         ap.error("--resume requires --ckpt (the directory to resume from)")
+    if args.server_momentum and not _sgd_server(args):
+        ap.error("--server-momentum needs the dual-batch SGD server path "
+                 "(--optimizer sgd, --scheme dbl/hybrid, no --micro-steps)")
+    if args.server_momentum and not (args.scan and args.fused):
+        ap.error("--server-momentum needs the fused scan loop "
+                 "(drop --no-scan-loop / --no-fused-merge)")
+    return args
 
+
+def run(argv=None):
+    """CLI entry: parse flags, pick the ``--arch`` config, train."""
+    args = parse_args(argv)
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
+    res, _ = train(cfg, args)
+    return [_to_cli_rec(r) for r in res.history]
+
+
+def train(cfg, args, *, log_every: int = 20, mesh=None):
+    """Everything after flag parsing, for any ``ModelConfig``.
+
+    ``args`` is a ``parse_args`` namespace; ``cfg`` replaces ``--arch`` /
+    ``--reduced``.  ``mesh`` spreads the dual-batch workers over devices
+    (``TrainEngine(mesh=...)``, the per-step sharded path).  Prints one
+    JSON record every ``log_every`` steps and returns
+    ``(RunResult, TrainEngine)``.
+    """
     data = SyntheticTokens(vocab=min(cfg.vocab_size, 256), seed=args.seed,
                            n_examples=max(4096, args.global_batch * 64))
     params = models.init_params(cfg, jax.random.PRNGKey(args.seed))
 
     spec = build_spec(args)
     phases = spec.to_phases()
-    # plain-SGD dual-batch -> the paper §3.4 server update (fused dbl_merge
-    # hot path).  That update has no momentum/weight-decay state, so the
-    # optimizer is built to match — otherwise the CLI would silently claim
-    # momentum it never applies.  Stateful optimizers (adamw) keep the
-    # weighted-mean path.
-    sgd_server = (args.optimizer == "sgd"
-                  and args.scheme in ("dbl", "hybrid")
-                  and args.micro_steps == 0)
-    if args.server_momentum and not sgd_server:
-        ap.error("--server-momentum needs the dual-batch SGD server path "
-                 "(--optimizer sgd, --scheme dbl/hybrid, no --micro-steps)")
-    if args.server_momentum and not (args.scan and args.fused):
-        ap.error("--server-momentum needs the fused scan loop "
-                 "(drop --no-scan-loop / --no-fused-merge)")
+    sgd_server = _sgd_server(args)
     if sgd_server:
         opt = make_optimizer("sgd", momentum=0.0, weight_decay=0.0)
         mom = (f"server momentum {args.server_momentum} in-kernel"
@@ -151,7 +178,7 @@ def run(argv=None):
                          scan_loop=("auto" if args.scan else False),
                          server_momentum=(args.server_momentum
                                           if sgd_server else 0.0),
-                         overlap_compile=args.overlap)
+                         overlap_compile=args.overlap, mesh=mesh)
 
     # the DataPlane is the batch_fn: counter-keyed per-(phase, worker,
     # step) streams (stateless in gstep, so a phase-boundary resume
@@ -165,16 +192,15 @@ def run(argv=None):
     res = api_run(spec,
                   RunConfig(backend="spmd", prefetch=args.prefetch,
                             ckpt_dir=args.ckpt or None, resume=args.resume,
-                            log_fn=log_fn),
+                            log_every=log_every, log_fn=log_fn),
                   init_params=params, opt_state=opt_state, engine=engine,
                   plane=plane)
-    history = [_to_cli_rec(r) for r in res.history]
     if res.resumed_from is not None:
         print(f"# resumed from phase boundary {res.resumed_from}")
     if args.ckpt:
         print(f"saved {len(phases) - (res.resumed_from or 0)} phase-boundary "
               f"checkpoint(s) -> {args.ckpt}")
-    return history
+    return res, engine
 
 
 def _to_cli_rec(rec: dict) -> dict:
@@ -184,4 +210,6 @@ def _to_cli_rec(rec: dict) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

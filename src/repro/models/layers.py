@@ -52,10 +52,11 @@ def apply_rope(x, positions, theta: float):
 
     Roll-based rotate-half: out = x·[cos,cos] + roll(x, hd/2)·[−sin,sin].
     Algebraically identical to the split/concat form, but never splits or
-    concatenates along the head dim: the jax 0.4.37 CPU SPMD partitioner
-    produces wrong values when a tensor that is model-sharded on that dim is
-    split/concatenated and combined elementwise with an in-graph concat
-    (tests/test_spmd.py guards the end-to-end parity).
+    concatenates along the head dim: the CPU SPMD partitioner of jax 0.4.37
+    produced wrong values when a tensor model-sharded on that dim was
+    split/concatenated and combined elementwise with an in-graph concat.
+    Under the installed jax 0.9.0 the roll form passes tests/test_spmd.py's
+    sharded-vs-single parity; the split form has not been re-tested there.
     """
     hd = x.shape[-1]
     freqs = jnp.asarray(rope_freqs(hd, theta))               # (hd/2,)

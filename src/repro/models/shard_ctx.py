@@ -20,7 +20,7 @@ import contextlib
 import threading
 
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 _state = threading.local()
 
@@ -74,5 +74,10 @@ def constrain(x, dims: str):
             spec.append(ctx["model"])
         else:
             spec.append(None)
-    return jax.lax.with_sharding_constraint(
-        x, jax.sharding.NamedSharding(ctx["mesh"], P(*spec)))
+    mesh = ctx["mesh"]
+    sharding = jax.sharding.NamedSharding(mesh, P(*spec))
+    if AxisType.Explicit in mesh.axis_types:
+        # explicit axes (``jax.make_mesh``'s default) carry the sharding in
+        # the array's type; a constraint may only name Auto axes
+        return jax.sharding.reshard(x, sharding)
+    return jax.lax.with_sharding_constraint(x, sharding)
