@@ -41,6 +41,7 @@ from typing import (Any, Callable, List, Optional, Protocol, Sequence,
                     runtime_checkable)
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint.ckpt import restore_latest, save_checkpoint
 from repro.cluster.simulator import simulate
@@ -317,11 +318,15 @@ class SpmdBackend:
                 self.engine.schedule_warm(phases[i + 1], params, opt_state,
                                           self.batch_fn)
             t0 = time.time()
-            params, opt_state, hist = self.engine.run(
-                [phase], params, opt_state, self.batch_fn, seed=seed,
-                start_step=gstep, start_samples=samples,
-                wall_offset=t_total, log_every=log_every, log_fn=log_fn,
-                phase_offset=i)
+            with TraceAnnotation("repro.train.phase", phase=i,
+                                 seq=phase.input_size,
+                                 batch=phase.batch_size,
+                                 steps=phase.n_steps):
+                params, opt_state, hist = self.engine.run(
+                    [phase], params, opt_state, self.batch_fn, seed=seed,
+                    start_step=gstep, start_samples=samples,
+                    wall_offset=t_total, log_every=log_every, log_fn=log_fn,
+                    phase_offset=i)
             dt = time.time() - t0
             for rec in hist:
                 history.append({**rec, "phase": i})

@@ -223,13 +223,14 @@ def data_fn_feed(data_fn: Callable, seed: int, *, prefetch: bool = True):
             return jax.device_put(stack_event_batches(batches, b_max))
 
         from repro.data.plane import prefetch_iter
+        steps = lambda r: r[1] - r[0]
         if not prefetch or len(ranges) <= 1:
-            yield from prefetch_iter(stage, ranges, None)
+            yield from prefetch_iter(stage, ranges, None, steps=steps)
             return
         from concurrent.futures import ThreadPoolExecutor
         with ThreadPoolExecutor(max_workers=1,
                                 thread_name_prefix="trace-feed") as ex:
-            yield from prefetch_iter(stage, ranges, ex)
+            yield from prefetch_iter(stage, ranges, ex, steps=steps)
     return feed
 
 

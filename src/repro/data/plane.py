@@ -50,27 +50,36 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Iterator, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.data.pipeline import stream_indices
 
 
-def prefetch_iter(stage, items, executor=None):
+def prefetch_iter(stage, items, executor=None, *, steps):
     """Double-buffered staging: yield ``stage(*item)`` for each item, with
     the NEXT item staged on ``executor`` (a single-worker pool — FIFO, so
     stateful stages keep their call order) while the caller consumes the
     current one.  ``executor=None`` stages synchronously.  The one shared
     prefetch loop behind ``DataPlane.scan_feed`` / ``DataPlane.trace_feed``
     / ``repro.cluster.trace.data_fn_feed``; cancels the in-flight future
-    if the consumer abandons the iterator early."""
+    if the consumer abandons the iterator early.
+
+    Where the consumer blocks on an item (the wait for the staging thread,
+    or the synchronous stage itself) is a ``repro.data.wait`` span whose
+    ``steps`` counter is ``steps(item)``, the steps the item stages."""
     items = list(items)
+    wait = lambda it: TraceAnnotation("repro.data.wait", steps=steps(it))
     if executor is None or len(items) <= 1:
         for it in items:
-            yield stage(*it)
+            with wait(it):
+                staged = stage(*it)
+            yield staged
         return
     fut = executor.submit(stage, *items[0])
     try:
         for i in range(len(items)):
-            staged = fut.result()
+            with wait(items[i]):
+                staged = fut.result()
             fut = (executor.submit(stage, *items[i + 1])
                    if i + 1 < len(items) else None)
             yield staged
@@ -244,7 +253,8 @@ class DataPlane:
 
             yield from prefetch_iter(
                 stage, ranges,
-                self._executor() if use_prefetch else None)
+                self._executor() if use_prefetch else None,
+                steps=lambda r: r[1] - r[0])
         return feed
 
     # -- double-buffered scan feed ----------------------------------------
@@ -259,10 +269,12 @@ class DataPlane:
         """Host-build + stack ``c`` consecutive batches and start their
         device upload (one ``device_put`` per key, no device round trip)."""
         import jax
-        batches = [self(phase, g0 + j) for j in range(c)]
-        stacked = {k: np.stack([b[k] for b in batches])
-                   for k in batches[0]}
-        return jax.device_put(stacked)
+        with TraceAnnotation("repro.data.stage", phase=self._locate(phase)[0],
+                             step=g0, steps=c):
+            batches = [self(phase, g0 + j) for j in range(c)]
+            stacked = {k: np.stack([b[k] for b in batches])
+                       for k in batches[0]}
+            return jax.device_put(stacked)
 
     def scan_feed(self, phase, start: int, n_steps: int,
                   chunk: int) -> Iterator[Tuple[int, dict]]:
@@ -278,6 +290,6 @@ class DataPlane:
             rem -= c
         staged_iter = prefetch_iter(self._stage_chunk, items,
                                     self._executor() if self.prefetch
-                                    else None)
+                                    else None, steps=lambda it: it[2])
         for (_, _, c), staged in zip(items, staged_iter):
             yield c, staged

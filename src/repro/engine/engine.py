@@ -46,6 +46,7 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core.flat import FlatSpec, flat_spec
 from repro.engine.phases import Phase
@@ -604,24 +605,50 @@ class TrainEngine:
         first = True
         for c, batches in self._chunk_feed(phase, batch_fn, gstep):
             g0 = gstep
-            rngs = (jax.vmap(lambda s: jax.random.fold_in(rng, s))(
-                jnp.arange(g0, g0 + c)) if drop > 0 else None)
-            fn, stall, warm = self._acquire_phase_fn(phase, spec, c,
-                                                     p2, v2, batches, rngs)
-            if first:
-                self._record_stall(pi + phase_offset, "scan", stall, warm)
-                first = False
-            p2, v2, losses = fn(p2, v2, batches, rngs)
-            losses = np.asarray(losses)     # one device sync per chunk
-            for j in range(c):
-                gstep += 1
-                samples_seen += phase.batch_size * phase.input_size
-                if gstep == start_step + 1 or gstep % log_every == 0:
-                    self._record(history, log_fn, gstep=gstep, pi=pi,
-                                 phase=phase, loss=losses[j],
-                                 samples_seen=samples_seen, t0=t0,
-                                 wall_offset=wall_offset)
+            with StepTraceAnnotation("repro.train.step", step_num=g0,
+                                     phase=pi + phase_offset, steps=c):
+                rngs = (jax.vmap(lambda s: jax.random.fold_in(rng, s))(
+                    jnp.arange(g0, g0 + c)) if drop > 0 else None)
+                with TraceAnnotation("repro.train.acquire"):
+                    fn, stall, warm = self._acquire_phase_fn(
+                        phase, spec, c, p2, v2, batches, rngs)
+                if first:
+                    self._record_stall(pi + phase_offset, "scan", stall,
+                                       warm)
+                    first = False
+                with TraceAnnotation("repro.train.dispatch"):
+                    p2, v2, losses = fn(p2, v2, batches, rngs)
+                with TraceAnnotation("repro.train.sync"):
+                    losses = np.asarray(losses)  # one device sync per chunk
+                for j in range(c):
+                    gstep += 1
+                    samples_seen += phase.batch_size * phase.input_size
+                    if gstep == start_step + 1 or gstep % log_every == 0:
+                        self._record(history, log_fn, gstep=gstep, pi=pi,
+                                     phase=phase, loss=losses[j],
+                                     samples_seen=samples_seen, t0=t0,
+                                     wall_offset=wall_offset)
         return p2, v2, gstep, samples_seen
+
+    def _ravel(self, params, opt_state):
+        """Enter the flat store: ``(spec, vspec, p2, v2)`` of the params
+        and, under server momentum, of the velocity."""
+        spec = self._param_spec(params)
+        if self._mixed:
+            p2 = (spec.ravel_jit(params), spec.ravel_master_jit(params))
+        else:
+            p2 = spec.ravel_jit(params)
+        vspec = v2 = None
+        if self.server_momentum > 0:
+            if not (isinstance(opt_state, dict) and "v" in opt_state):
+                raise ValueError(
+                    "server_momentum needs an opt_state with a "
+                    'params-shaped "v" tree (e.g. sgd_momentum)')
+            vspec = self._param_spec(opt_state["v"])
+            # the velocity stays f32 whatever the store dtype
+            # (ravel_master IS ravel on an f32 spec)
+            v2 = vspec.ravel_master_jit(opt_state["v"])
+        return spec, vspec, p2, v2
 
     def run(self, phases: Sequence[Phase], params, opt_state,
             batch_fn: Callable[[Phase, int], dict], *,
@@ -659,7 +686,9 @@ class TrainEngine:
         def materialize():
             """Leave the flat store: params/opt_state become current."""
             nonlocal params, opt_state, flat
-            if flat is not None:
+            if flat is None:
+                return
+            with TraceAnnotation("repro.train.unravel"):
                 spec, vspec, p2, v2 = flat
                 # mixed precision carries (shadow, master); the f32 master
                 # is the value of record — checkpoints and downstream
@@ -669,7 +698,7 @@ class TrainEngine:
                     # the velocity's OWN spec — its leaf dtypes may differ
                     # from the params' (e.g. f32 state over bf16 params)
                     opt_state = dict(opt_state, v=vspec.unravel_jit(v2))
-                flat = None
+            flat = None
 
         def warm_next(pi):
             """Overlap phase pi+1's compile with phase pi's execution."""
@@ -703,23 +732,8 @@ class TrainEngine:
             kind = self._kind_for(phase)
             if self._use_scan(kind):
                 if flat is None:
-                    spec = self._param_spec(params)
-                    if self._mixed:
-                        p2 = (spec.ravel_jit(params),
-                              spec.ravel_master_jit(params))
-                    else:
-                        p2 = spec.ravel_jit(params)
-                    vspec = v2 = None
-                    if mom > 0:
-                        if not (isinstance(opt_state, dict)
-                                and "v" in opt_state):
-                            raise ValueError(
-                                "server_momentum needs an opt_state with a "
-                                'params-shaped "v" tree (e.g. sgd_momentum)')
-                        vspec = self._param_spec(opt_state["v"])
-                        # the velocity stays f32 whatever the store dtype
-                        # (ravel_master IS ravel on an f32 spec)
-                        v2 = vspec.ravel_master_jit(opt_state["v"])
+                    with TraceAnnotation("repro.train.ravel"):
+                        spec, vspec, p2, v2 = self._ravel(params, opt_state)
                 else:
                     spec, vspec, p2, v2 = flat
                 flat = (spec, vspec, p2, v2)

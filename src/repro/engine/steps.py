@@ -211,25 +211,30 @@ def make_fused_phase_scan(cfg, layout, spec, *, lr: float,
             # gradient, but it arrives f32: the backward stays off the
             # emulated-bf16 path (2.4x slower on CPU) and the kernel's
             # master update consumes it unrounded
-            (loss, _), g2 = jax.value_and_grad(merged_loss, has_aux=True)(
-                shadow.astype(jnp.float32) if mixed else shadow, batch, rng)
-            if mixed:
-                master = p2[1]
+            with jax.named_scope("loss_and_grad"):
+                p_in = shadow.astype(jnp.float32) if mixed else shadow
+                (loss, _), g2 = jax.value_and_grad(
+                    merged_loss, has_aux=True)(p_in, batch, rng)
+            with jax.named_scope("update"):
+                if mixed:
+                    master = p2[1]
+                    if mom > 0:
+                        shadow, master, v2 = dbl_apply_flat2d(
+                            shadow, g2, vel2=v2, lr=lr_f, momentum=mom,
+                            master2=master, interpret=interpret)
+                    else:
+                        shadow, master = dbl_apply_flat2d(
+                            shadow, g2, lr=lr_f, master2=master,
+                            interpret=interpret)
+                    return (shadow, master), v2, loss
                 if mom > 0:
-                    shadow, master, v2 = dbl_apply_flat2d(
-                        shadow, g2, vel2=v2, lr=lr_f, momentum=mom,
-                        master2=master, interpret=interpret)
+                    p2, v2 = dbl_apply_flat2d(p2, g2, vel2=v2, lr=lr_f,
+                                              momentum=mom,
+                                              interpret=interpret)
                 else:
-                    shadow, master = dbl_apply_flat2d(
-                        shadow, g2, lr=lr_f, master2=master,
-                        interpret=interpret)
-                return (shadow, master), v2, loss
-            if mom > 0:
-                p2, v2 = dbl_apply_flat2d(p2, g2, vel2=v2, lr=lr_f,
-                                          momentum=mom, interpret=interpret)
-            else:
-                p2 = dbl_apply_flat2d(p2, g2, lr=lr_f, interpret=interpret)
-            return p2, v2, loss
+                    p2 = dbl_apply_flat2d(p2, g2, lr=lr_f,
+                                          interpret=interpret)
+                return p2, v2, loss
 
         xs = (batches, rngs) if rngs is not None else batches
         if mom > 0:

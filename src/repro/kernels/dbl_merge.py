@@ -150,9 +150,10 @@ def _kernel_apply_master_vel(p_ref, m_ref, g_ref, v_ref, op_ref, om_ref,
     op_ref[...] = m.astype(op_ref.dtype)
 
 
-def _launch(kernel, ins, out_shape, aliases, *, interpret, block_rows):
-    """One ``pallas_call`` over same-shaped flat buffers: a single
-    whole-buffer block up to ``MAX_WHOLE_ROWS`` rows, a 1-D grid of
+def _launch(kernel, ins, out_shape, aliases, *, interpret, block_rows,
+            name):
+    """One ``pallas_call`` named ``name`` over same-shaped flat buffers: a
+    single whole-buffer block up to ``MAX_WHOLE_ROWS`` rows, a 1-D grid of
     ``block_rows``-row tiles beyond (the codec pads rows to the matching
     multiple).  Counts as exactly one launch."""
     global _LAUNCHES
@@ -164,7 +165,7 @@ def _launch(kernel, ins, out_shape, aliases, *, interpret, block_rows):
         # whole-buffer block: no grid machinery, no index maps
         return pl.pallas_call(kernel, out_shape=out_shape,
                               interpret=interpret,
-                              input_output_aliases=aliases)(*ins)
+                              input_output_aliases=aliases, name=name)(*ins)
     if rows % block_rows:
         raise ValueError(
             f"flat buffer of {rows} rows cannot grid over "
@@ -176,7 +177,7 @@ def _launch(kernel, ins, out_shape, aliases, *, interpret, block_rows):
     return pl.pallas_call(kernel, grid=(rows // block_rows,),
                           in_specs=[spec] * len(ins), out_specs=out_specs,
                           out_shape=out_shape, interpret=interpret,
-                          input_output_aliases=aliases)(*ins)
+                          input_output_aliases=aliases, name=name)(*ins)
 
 
 def _sds(x):
@@ -206,24 +207,28 @@ def dbl_merge_flat2d(p2, gl2, gs2, *, factor: float, lr: float,
             return _launch(
                 functools.partial(_kernel_master, factor=factor, lr=lr),
                 (p2, master2, gl2, gs2), (_sds(p2), _sds(master2)),
-                {0: 0, 1: 1}, interpret=interpret, block_rows=block_rows)
+                {0: 0, 1: 1}, interpret=interpret, block_rows=block_rows,
+                name="dbl_merge_flat2d")
         return _launch(
             functools.partial(_kernel_master_vel, factor=factor, lr=lr,
                               momentum=momentum),
             (p2, master2, gl2, gs2, vel2),
             (_sds(p2), _sds(master2), _sds(vel2)),
-            {0: 0, 1: 1, 4: 2}, interpret=interpret, block_rows=block_rows)
+            {0: 0, 1: 1, 4: 2}, interpret=interpret, block_rows=block_rows,
+            name="dbl_merge_flat2d")
     if vel2 is None:
         return _launch(functools.partial(_kernel, factor=factor, lr=lr),
                        (p2, gl2, gs2),
                        jax.ShapeDtypeStruct(p2.shape, p2.dtype), {0: 0},
-                       interpret=interpret, block_rows=block_rows)
+                       interpret=interpret, block_rows=block_rows,
+                       name="dbl_merge_flat2d")
     return _launch(functools.partial(_kernel_vel, factor=factor, lr=lr,
                                      momentum=momentum),
                    (p2, gl2, gs2, vel2),
                    (jax.ShapeDtypeStruct(p2.shape, p2.dtype),
                     jax.ShapeDtypeStruct(vel2.shape, vel2.dtype)),
-                   {0: 0, 3: 1}, interpret=interpret, block_rows=block_rows)
+                   {0: 0, 3: 1}, interpret=interpret, block_rows=block_rows,
+                   name="dbl_merge_flat2d")
 
 
 def dbl_apply_flat2d(p2, g2, *, lr: float, vel2=None, momentum: float = 0.0,
@@ -248,23 +253,27 @@ def dbl_apply_flat2d(p2, g2, *, lr: float, vel2=None, momentum: float = 0.0,
             return _launch(
                 functools.partial(_kernel_apply_master, lr=lr),
                 (p2, master2, g2), (_sds(p2), _sds(master2)), {0: 0, 1: 1},
-                interpret=interpret, block_rows=block_rows)
+                interpret=interpret, block_rows=block_rows,
+                name="dbl_apply_flat2d")
         return _launch(
             functools.partial(_kernel_apply_master_vel, lr=lr,
                               momentum=momentum),
             (p2, master2, g2, vel2),
             (_sds(p2), _sds(master2), _sds(vel2)),
-            {0: 0, 1: 1, 3: 2}, interpret=interpret, block_rows=block_rows)
+            {0: 0, 1: 1, 3: 2}, interpret=interpret, block_rows=block_rows,
+            name="dbl_apply_flat2d")
     if vel2 is None:
         return _launch(functools.partial(_kernel_apply, lr=lr), (p2, g2),
                        jax.ShapeDtypeStruct(p2.shape, p2.dtype), {0: 0},
-                       interpret=interpret, block_rows=block_rows)
+                       interpret=interpret, block_rows=block_rows,
+                       name="dbl_apply_flat2d")
     return _launch(functools.partial(_kernel_apply_vel, lr=lr,
                                      momentum=momentum),
                    (p2, g2, vel2),
                    (jax.ShapeDtypeStruct(p2.shape, p2.dtype),
                     jax.ShapeDtypeStruct(vel2.shape, vel2.dtype)),
-                   {0: 0, 2: 1}, interpret=interpret, block_rows=block_rows)
+                   {0: 0, 2: 1}, interpret=interpret, block_rows=block_rows,
+                   name="dbl_apply_flat2d")
 
 
 def _kernel_apply_worker(wid_ref, lr_ref, fac_ref, mom_ref, p_ref, g_ref,
@@ -363,7 +372,8 @@ def dbl_apply_worker_flat2d(p2, g2, vel3, wid, lr, factor,
     if rows <= MAX_WHOLE_ROWS and n_workers * rows <= 2 * MAX_WHOLE_ROWS:
         return pl.pallas_call(kernel, out_shape=out_shape,
                               interpret=interpret,
-                              input_output_aliases=aliases)(
+                              input_output_aliases=aliases,
+                              name="dbl_apply_worker_flat2d")(
             *scalars, *bufs)
     block = _worker_block_rows(rows, n_workers, block_rows)
     if rows % block:
@@ -381,7 +391,8 @@ def dbl_apply_worker_flat2d(p2, g2, vel3, wid, lr, factor,
         kernel, grid=(rows // block,),
         in_specs=[sspec] * 4 + bspecs,
         out_specs=ospecs, out_shape=out_shape,
-        interpret=interpret, input_output_aliases=aliases)(
+        interpret=interpret, input_output_aliases=aliases,
+        name="dbl_apply_worker_flat2d")(
         *scalars, *bufs)
 
 

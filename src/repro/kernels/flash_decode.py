@@ -142,6 +142,7 @@ def flash_decode(q, k_cache, v_cache, pos, *, window: int = 0,
             pltpu.VMEM((1, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_decode",
     )(pos_arr, q, k_cache, v_cache)
 
 
@@ -263,6 +264,7 @@ def flash_decode_paged(q, k_pages, v_pages, page_table, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((ns, n_rep, kvh, hd), q.dtype),
         interpret=interpret,
+        name="flash_decode_paged",
     )(table, lens, qg, k_pages, v_pages)
     return out.transpose(0, 2, 1, 3).reshape(ns, h, 1, hd)
 

@@ -93,4 +93,5 @@ def mamba_ssd_scan(x, dt, A_log, B, C, D_skip, *, chunk: int = 128,
         out_shape=jax.ShapeDtypeStruct((bt, h, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
+        name="mamba_ssd_scan",
     )(x, dt2, alog2, B, C, d2)

@@ -65,4 +65,5 @@ def wkv6_chunked(r, k, v, w, u, *, chunk: int = 128,
         out_shape=jax.ShapeDtypeStruct((b, h, s, vd), r.dtype),
         scratch_shapes=[pltpu.VMEM((kd, vd), jnp.float32)],
         interpret=interpret,
+        name="wkv6_chunked",
     )(r, k, v, w, u2)

@@ -59,6 +59,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serve import paged as pg
 from repro.serve.draft import accepted_prefix_len, propose_ngram
@@ -222,11 +223,13 @@ class ServeEngine:
             toks, logits, self._caches = fn(
                 self.params, self._caches, rows, lengths, active, tokens,
                 rids, steps0)
-            return np.asarray(toks), logits
-        fn = self._row_fn if kind == "rows" else self._step_fn
-        logits, self._caches = fn(self.params, self._caches, rows,
-                                  lengths, active, tokens)
-        return np.asarray(jnp.argmax(logits, axis=-1), np.int32), logits
+        else:
+            fn = self._row_fn if kind == "rows" else self._step_fn
+            logits, self._caches = fn(self.params, self._caches, rows,
+                                      lengths, active, tokens)
+            toks = jnp.argmax(logits, axis=-1)
+        with TraceAnnotation("repro.serve.readback"):
+            return np.asarray(toks, np.int32), logits
 
     # ------------------------ scheduler hooks -------------------------
     def admit(self, slot: int, req: Request, pages: Tuple[int, ...], *,

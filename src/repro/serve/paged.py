@@ -253,23 +253,27 @@ def make_serve_step(cfg: ModelConfig, spec: PageSpec,
                 p, ck, cv, w = xs
                 xin = rms_norm(x, p["ln1"], cfg.norm_eps)
                 q, k, v = attn_project_qkv(p["attn"], xin, positions, cfg)
-                ck = write_kv(ck, k, rows, positions, active)
-                cv = write_kv(cv, v, rows, positions, active)
-                if flash:
-                    o = fd.flash_decode_paged(
-                        q.transpose(0, 2, 1, 3), ck, cv, rows, lengths,
-                        window=uniform_w).transpose(0, 2, 1, 3)
-                else:
-                    o = _slot_attention(q, read_kv(ck, rows, m),
-                                        read_kv(cv, rows, m), positions, w)
+                with jax.named_scope("write_kv"):
+                    ck = write_kv(ck, k, rows, positions, active)
+                    cv = write_kv(cv, v, rows, positions, active)
+                with jax.named_scope("attention"):
+                    if flash:
+                        o = fd.flash_decode_paged(
+                            q.transpose(0, 2, 1, 3), ck, cv, rows, lengths,
+                            window=uniform_w).transpose(0, 2, 1, 3)
+                    else:
+                        o = _slot_attention(q, read_kv(ck, rows, m),
+                                            read_kv(cv, rows, m), positions,
+                                            w)
                 h = x + o.reshape(m, t, cfg.n_heads * cfg.head_dim) \
                     @ p["attn"]["wo"]
                 hin = rms_norm(h, p["ln2"], cfg.norm_eps)
-                if cfg.moe:
-                    y, _ = moe_ffn(p["moe"], hin, cfg.moe, dropless=True)
-                else:
-                    y = swiglu(hin, p["mlp"]["wi"], p["mlp"]["wg"],
-                               p["mlp"]["wo"])
+                with jax.named_scope("mlp"):
+                    if cfg.moe:
+                        y, _ = moe_ffn(p["moe"], hin, cfg.moe, dropless=True)
+                    else:
+                        y = swiglu(hin, p["mlp"]["wi"], p["mlp"]["wg"],
+                                   p["mlp"]["wo"])
                 return h + y, (ck, cv)
 
             x, (ck, cv) = jax.lax.scan(

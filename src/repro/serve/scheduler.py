@@ -49,6 +49,7 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serve.paged import PageSpec
 
@@ -415,28 +416,31 @@ def run_serve_loop(requests: Sequence[Request], spec: PageSpec, hooks, *,
             registry.register(toks[:(p // pl) * pl], toks[(p // pl) * pl:],
                               s.pages[p // pl])
 
-    while pending or queue or any(s is not None for s in slots):
-        if tick >= max_ticks:
-            raise RuntimeError(f"serve loop exceeded {max_ticks} ticks")
-
+    def _tick(span) -> None:
+        """One tick: admit, one prefill chunk, one decode, evict.  ``span``
+        is the tick's ``repro.serve.tick`` span; its counters are set as
+        they become known."""
         while pending and pending[0].arrival <= tick:
             queue.append(pending.pop(0))
 
         # -- admission ---------------------------------------------------
-        if policy == "continuous":
-            # head-of-line FCFS: never skip past a request that doesn't
-            # fit — determinism and no starvation of large requests
-            while queue and any(s is None for s in slots):
-                if not pool.can_alloc(_plan(queue[0])[1]):
-                    break
-                _admit(queue.pop(0))
-        else:
-            # static: wait for the previous batch to fully drain, then
-            # for a full batch (unless no more requests will ever arrive)
-            if all(s is None for s in slots) and queue and (
-                    len(queue) >= batch_n or not pending):
-                for _ in range(min(batch_n, len(queue), spec.n_slots)):
+        with TraceAnnotation("repro.serve.admit"):
+            if policy == "continuous":
+                # head-of-line FCFS: never skip past a request that doesn't
+                # fit — determinism and no starvation of large requests
+                while queue and any(s is None for s in slots):
+                    if not pool.can_alloc(_plan(queue[0])[1]):
+                        break
                     _admit(queue.pop(0))
+            else:
+                # static: wait for the previous batch to fully drain, then
+                # for a full batch (unless no more requests will ever arrive)
+                if all(s is None for s in slots) and queue and (
+                        len(queue) >= batch_n or not pending):
+                    for _ in range(min(batch_n, len(queue), spec.n_slots)):
+                        _admit(queue.pop(0))
+        span.set_metadata(queued=len(queue), prefilling=sum(
+            1 for s in slots if s is not None and s.state == "prefill"))
 
         # -- one chunked-prefill call (large-batch regime) ---------------
         for slot, s in enumerate(slots):
@@ -458,7 +462,9 @@ def run_serve_loop(requests: Sequence[Request], spec: PageSpec, hooks, *,
             pos = s.prefilled
             s.prefilled += len(chunk)
             last = s.prefilled >= len(s.req.tokens)
-            hooks.prefill(slot, s.req, chunk, pos, last)
+            with TraceAnnotation("repro.serve.prefill", rid=s.req.rid,
+                                 slot=slot, pos=pos, tokens=len(chunk)):
+                hooks.prefill(slot, s.req, chunk, pos, last)
             log.append(("prefill", tick, s.req.rid, slot, pos,
                         len(chunk), last))
             _register(s, last)
@@ -471,8 +477,10 @@ def run_serve_loop(requests: Sequence[Request], spec: PageSpec, hooks, *,
         live = tuple(i for i, s in enumerate(slots)
                      if s is not None and s.state == "decode"
                      and s.generated < s.req.max_new)
+        span.set_metadata(decoding=len(live))
         if live:
-            ret = hooks.decode(live)
+            with TraceAnnotation("repro.serve.decode", rows=len(live)):
+                ret = hooks.decode(live)
             counts = tuple(1 for _ in live) if ret is None else \
                 tuple(int(ret[i]) for i in live)
             for i, n in zip(live, counts):
@@ -485,19 +493,29 @@ def run_serve_loop(requests: Sequence[Request], spec: PageSpec, hooks, *,
             log.append(("decode", tick, live, counts))
 
         # -- completion / eviction ---------------------------------------
-        for slot, s in enumerate(slots):
-            if s is None or s.state != "decode":
-                continue
-            done = s.generated >= s.req.max_new
-            if not done and finished_hook is not None and slot in live:
-                done = bool(finished_hook(slot, s.req))
-            if done:
-                hooks.evict(slot, s.req)
-                for p in pool.free(s.req.rid):
-                    if registry is not None:
-                        registry.drop_page(p)
-                slots[slot] = None
-                log.append(("evict", tick, s.req.rid, slot))
+        with TraceAnnotation("repro.serve.evict") as evict_span:
+            evicted = 0
+            for slot, s in enumerate(slots):
+                if s is None or s.state != "decode":
+                    continue
+                done = s.generated >= s.req.max_new
+                if not done and finished_hook is not None and slot in live:
+                    done = bool(finished_hook(slot, s.req))
+                if done:
+                    hooks.evict(slot, s.req)
+                    for p in pool.free(s.req.rid):
+                        if registry is not None:
+                            registry.drop_page(p)
+                    slots[slot] = None
+                    log.append(("evict", tick, s.req.rid, slot))
+                    evicted += 1
+            evict_span.set_metadata(evicted=evicted)
+
+    while pending or queue or any(s is not None for s in slots):
+        if tick >= max_ticks:
+            raise RuntimeError(f"serve loop exceeded {max_ticks} ticks")
+        with TraceAnnotation("repro.serve.tick", tick=tick) as tick_span:
+            _tick(tick_span)
         tick += 1
 
     pool.audit()
