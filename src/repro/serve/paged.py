@@ -18,8 +18,8 @@ Two step builders share ONE attention-math path (`_slot_attention`):
   * ``backend="paged"``   — gather KV through the page table (XLA), or
     stream pages with ``kernels.flash_decode.flash_decode_paged`` on TPU
     (the page table rides scalar prefetch, so no gather materializes).
-  * ``backend="contig"``  — classic per-slot contiguous cache, reading the
-    cache directly.
+  * ``backend="contig"``  — classic per-slot contiguous cache, stored
+    lane-dense (``init_contig_cache``) and read directly.
 
 Because the two backends differ only in how bytes are addressed — the
 values entering the attention math are identical, and masked positions
@@ -149,9 +149,14 @@ def init_paged_cache(cfg: ModelConfig, spec: PageSpec) -> List[dict]:
 
 def init_contig_cache(cfg: ModelConfig, spec: PageSpec) -> List[dict]:
     """Contiguous baseline caches with the SAME logical extent as the
-    paged pool (``slot_tokens`` per slot) — the bit-parity contract."""
-    kv, hd = cfg.n_kv_heads, cfg.head_dim
-    shape = (spec.n_slots, spec.slot_tokens, kv, hd)
+    paged pool (``slot_tokens`` per slot) — the bit-parity contract.
+
+    Stored lane-dense, ``(count, n_slots, slot_tokens, kv * hd)``: a head
+    dim that is no lane multiple (phi3's 96) would otherwise lead the TPU
+    compiler to store the donated cache transposed and convert all of it
+    in and out of every step.  The step views a row as ``(kv, hd)`` only
+    where attention reads it."""
+    shape = (spec.n_slots, spec.slot_tokens, cfg.n_kv_heads * cfg.head_dim)
     return [{"k": jnp.zeros((s.count,) + shape, spec.store_dtype),
              "v": jnp.zeros((s.count,) + shape, spec.store_dtype)}
             for s in attention_segments(cfg)]
@@ -218,24 +223,32 @@ def make_serve_step(cfg: ModelConfig, spec: PageSpec,
     # auto-selection contract as dbl_merge's update="auto".
     use_flash = paged and fd.resolve_impl("auto") == "pallas"
 
-    def write_kv(ck, k, rows, positions, active):
+    kv, hd = cfg.n_kv_heads, cfg.head_dim
+
+    # ``ck`` is a segment's whole stacked cache, carried through the layer
+    # scan and written in place at layer ``li``: only the chunk's tokens
+    # move, never a layer or the whole cache.
+    def write_kv(ck, li, k, rows, positions, active):
         valid = jnp.arange(positions.shape[1])[None, :] < active[:, None]
         ok = jnp.logical_and(valid, positions < slot_tokens)
-        off = positions % page_len
+        k = k.astype(ck.dtype)
         if paged:
             pi = jnp.take_along_axis(
                 rows, jnp.clip(positions // page_len, 0, pp - 1), axis=1)
             pi = jnp.where(ok, pi, n_pages)     # OOB page index -> dropped
-            return ck.at[pi, off].set(k.astype(ck.dtype), mode="drop")
+            return ck.at[li, pi, positions % page_len].set(k, mode="drop")
         pos_w = jnp.where(ok, positions, slot_tokens)
-        return ck.at[rows[:, None], pos_w].set(k.astype(ck.dtype),
-                                               mode="drop")
+        return ck.at[li, rows[:, None], pos_w].set(
+            k.reshape(k.shape[:2] + (kv * hd,)), mode="drop")
 
-    def read_kv(ck, rows, m):
+    def layer_kv(ck, li):
+        return jax.lax.dynamic_index_in_dim(ck, li, keepdims=False)
+
+    def read_kv(ck, li, rows, m):
         if paged:
-            return ck[rows].reshape(m, slot_tokens,
-                                    cfg.n_kv_heads, cfg.head_dim)
-        return ck[rows] if gather_rows else ck
+            return ck[li, rows].reshape(m, slot_tokens, kv, hd)
+        c = ck[li, rows] if gather_rows else layer_kv(ck, li)
+        return c.reshape(c.shape[:2] + (kv, hd))
 
     def step(params, caches, rows, lengths, active, tokens):
         m, t = tokens.shape
@@ -249,22 +262,24 @@ def make_serve_step(cfg: ModelConfig, spec: PageSpec,
             flash = use_flash and t == 1 and uniform_w is not None
             windows = jnp.asarray(seg.windows, jnp.int32)
 
-            def body(x, xs, flash=flash, uniform_w=uniform_w):
-                p, ck, cv, w = xs
+            def body(carry, xs, flash=flash, uniform_w=uniform_w):
+                x, ck, cv = carry
+                p, w, li = xs
                 xin = rms_norm(x, p["ln1"], cfg.norm_eps)
                 q, k, v = attn_project_qkv(p["attn"], xin, positions, cfg)
                 with jax.named_scope("write_kv"):
-                    ck = write_kv(ck, k, rows, positions, active)
-                    cv = write_kv(cv, v, rows, positions, active)
+                    ck = write_kv(ck, li, k, rows, positions, active)
+                    cv = write_kv(cv, li, v, rows, positions, active)
                 with jax.named_scope("attention"):
                     if flash:
                         o = fd.flash_decode_paged(
-                            q.transpose(0, 2, 1, 3), ck, cv, rows, lengths,
+                            q.transpose(0, 2, 1, 3), layer_kv(ck, li),
+                            layer_kv(cv, li), rows, lengths,
                             window=uniform_w).transpose(0, 2, 1, 3)
                     else:
-                        o = _slot_attention(q, read_kv(ck, rows, m),
-                                            read_kv(cv, rows, m), positions,
-                                            w)
+                        o = _slot_attention(q, read_kv(ck, li, rows, m),
+                                            read_kv(cv, li, rows, m),
+                                            positions, w)
                 h = x + o.reshape(m, t, cfg.n_heads * cfg.head_dim) \
                     @ p["attn"]["wo"]
                 hin = rms_norm(h, p["ln2"], cfg.norm_eps)
@@ -274,10 +289,11 @@ def make_serve_step(cfg: ModelConfig, spec: PageSpec,
                     else:
                         y = swiglu(hin, p["mlp"]["wi"], p["mlp"]["wg"],
                                    p["mlp"]["wo"])
-                return h + y, (ck, cv)
+                return (h + y, ck, cv), None
 
-            x, (ck, cv) = jax.lax.scan(
-                body, x, (sp, cache["k"], cache["v"], windows))
+            (x, ck, cv), _ = jax.lax.scan(
+                body, (x, cache["k"], cache["v"]),
+                (sp, windows, jnp.arange(seg.count, dtype=jnp.int32)))
             new_caches.append({"k": ck, "v": cv})
 
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)

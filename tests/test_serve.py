@@ -225,6 +225,56 @@ def test_serve_matches_reference_decode_loop(gemma):
     assert rec.tokens == out
 
 
+def test_contig_step_writes_only_new_tokens(gemma):
+    """One prefill chunk into one slot, one decode step, and a chunk that
+    runs past the slot's end, each against a copy of the cache taken
+    before: the step writes exactly positions ``[length, length +
+    active)`` of its own slots, below ``slot_tokens``, and leaves every
+    other slot and position bit-identical."""
+    cfg, params = gemma
+    spec = PageSpec(page_len=16, pages_per_slot=2, n_slots=3)
+    s_tok = spec.slot_tokens
+    rng = np.random.default_rng(0)
+    caches = [{n: jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+               for n, a in c.items()} for c in pg.init_contig_cache(cfg, spec)]
+    prefill = jax.jit(pg.make_serve_step(cfg, spec, "contig",
+                                         gather_rows=True))
+    decode = jax.jit(pg.make_serve_step(cfg, spec, "contig"))
+
+    def run(fn, caches, rows, lengths, active, t):
+        m = len(rows)
+        toks = rng.integers(0, cfg.vocab_size, (m, t)).astype(np.int32)
+        before = [{n: np.asarray(a) for n, a in c.items()} for c in caches]
+        _, after = fn(params, caches, np.asarray(rows, np.int32),
+                      np.asarray(lengths, np.int32),
+                      np.asarray(active, np.int32), toks)
+        written = np.zeros((spec.n_slots, s_tok), bool)
+        for r, n0, a in zip(rows, lengths, active):
+            written[r, n0:min(n0 + a, s_tok)] = True
+        for b, c in zip(before, after):
+            for n in ("k", "v"):
+                a = np.asarray(c[n])
+                assert a.shape == b[n].shape
+                assert np.array_equal(a[:, ~written], b[n][:, ~written])
+                assert np.all(np.any(a[:, written] != b[n][:, written],
+                                     axis=-1))
+        return after, toks
+
+    caches, _ = run(prefill, caches, [1], [5], [6], 8)
+    caches, _ = run(decode, caches, [0, 1, 2], [7, 11, 0], [1, 1, 0], 1)
+    # past the slot's end: positions s_tok - 4 .. s_tok + 3, four written
+    tail, toks = run(prefill, caches, [2], [s_tok - 4], [8], 8)
+    # nothing shifted: the four positions hold what a chunk of exactly
+    # those four tokens writes there
+    _, short = prefill(params, caches, np.asarray([2], np.int32),
+                       np.asarray([s_tok - 4], np.int32),
+                       np.asarray([4], np.int32), toks)
+    for c, d in zip(tail, short):
+        for n in ("k", "v"):
+            assert np.array_equal(np.asarray(c[n])[:, 2, -4:],
+                                  np.asarray(d[n])[:, 2, -4:])
+
+
 # ------------------------- engine behavior ---------------------------------
 def test_continuous_equals_static_tokens_and_buckets(gemma):
     cfg, params = gemma

@@ -5,13 +5,16 @@ topology that is described rather than attached.  These cases catch what
 interpret mode cannot — block shapes the tiling rule refuses, kernels that
 ask for too much fast memory — at the published widths the program runs
 (phi3-mini: 32 heads of 96, kv 32; its 4-layer flat store of 5,079,040
-rows).  Every case asserts that the compiled program holds the Pallas
-kernel (``tpu_custom_call``), not an XLA fallback.
+rows).  Every kernel case asserts that the compiled program holds the
+Pallas kernel (``tpu_custom_call``), not an XLA fallback; the serve-step
+cases assert what the compiler does with the donated KV cache.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -97,3 +100,55 @@ def test_flash_decode_paged_bf16(one_chip, heads, kv_heads, window):
     _assert_kernel(lambda *a: flash_decode_paged(*a, window=window,
                                                  interpret=False),
                    q, pool, pool, table, lengths)
+
+
+# phi3_serve_contig's KV cache: 4 layers, 62 slots of 2048 tokens, kv 32 x 96
+SERVE_SLOTS, SLOT_TOKENS, SERVE_LAYERS = 62, 2048, 4
+
+
+@pytest.mark.parametrize("m,t,gather_rows", [(SERVE_SLOTS, 1, False),
+                                             (1, 256, True)],
+                         ids=["decode_62x1", "prefill_1x256"])
+def test_serve_step_writes_donated_cache_in_place(one_chip, m, t,
+                                                  gather_rows):
+    """The contiguous serve step at phi3-mini widths writes its new tokens
+    into the donated cache where it lies: no copy of the cache, whole or
+    one layer of it, in the cache's own dtype; temporaries under the
+    cache's own bytes; the cache aliased from input to output.  (Decode's
+    f32 upcast of each layer's K and V is the attention's, not a cache
+    copy.)"""
+    from repro.configs import get_config
+    from repro.models import transformer as tf
+    from repro.serve import paged as pg
+
+    cfg = dataclasses.replace(get_config("phi3-mini-3.8b"),
+                              n_layers=SERVE_LAYERS)
+    spec = pg.PageSpec(page_len=16, pages_per_slot=SLOT_TOKENS // 16,
+                       n_slots=SERVE_SLOTS, store_dtype=jnp.bfloat16)
+    struct = lambda tree: jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+    params = struct(jax.eval_shape(
+        lambda: tf.init_params(cfg, jax.random.PRNGKey(0))))
+    caches = struct(jax.eval_shape(lambda: pg.init_contig_cache(cfg, spec)))
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for c in caches for a in c.values())
+    i32 = lambda *s: _sds(s, jnp.int32, one_chip)
+    fn = jax.jit(pg.make_token_fn(cfg, spec, "contig",
+                                  gather_rows=gather_rows),
+                 donate_argnums=(1,))
+    compiled = fn.lower(params, caches, i32(m), i32(m), i32(m), i32(m, t),
+                        i32(m), i32(m)).compile()
+
+    kv = (cfg.n_kv_heads * cfg.head_dim,)
+    split = (cfg.n_kv_heads, cfg.head_dim)
+    layer = (SERVE_SLOTS, SLOT_TOKENS)
+    banned = {(SERVE_LAYERS,) + layer + kv, (SERVE_LAYERS,) + layer + split,
+              layer + kv, layer + split}
+    copies = re.findall(r"= bf16\[([\d,]*)\]\{[^}]*\} copy\(",
+                        compiled.as_text())
+    shapes = [tuple(int(d) for d in c.split(",") if d and d != "1")
+              for c in copies]
+    assert not [s for s in shapes if s in banned]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < cache_bytes
+    assert mem.alias_size_in_bytes >= cache_bytes
